@@ -179,7 +179,6 @@ from repro.launch.mesh import make_cohort_mesh
 from repro.models.api import Model
 from repro.sharding.specs import (batch_axes, cohort_spec,
                                   sim_mesh_config)
-from repro.utils.compat import shard_map
 
 __all__ = ["CANON_BLOCKS", "EngineState", "FaultConfig",
            "POPULATION_BACKENDS", "SAMPLERS", "SimEngine", "canon_pad",
@@ -822,15 +821,18 @@ class SimEngine:
         # cspec is a pytree *prefix*: it shards every batch_args leaf along
         # its leading cohort-slot axis, whatever the backend's tuple layout.
         # The fault-free signature is kept verbatim so fault-off programs
-        # trace exactly as before.
+        # trace exactly as before. check_vma is off: the body closes over
+        # replicated population constants.
         if corrupt is None:
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 body, mesh=self.mesh,
-                in_specs=(P(), cspec, cspec), out_specs=P())
+                in_specs=(P(), cspec, cspec), out_specs=P(),
+                check_vma=False)
             return sharded(params, batch_args, slot_mask)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             body, mesh=self.mesh,
-            in_specs=(P(), cspec, cspec, cspec), out_specs=P())
+            in_specs=(P(), cspec, cspec, cspec), out_specs=P(),
+            check_vma=False)
         return sharded(params, batch_args, slot_mask, corrupt)
 
     def _pop_shard_body(self, rank, k_avail, k_sample, round_idx,
@@ -919,10 +921,11 @@ class SimEngine:
                                             synth, axes=axes)
 
             n_out = 5 if self.faults is None else 6
-            out = shard_map(
+            out = jax.shard_map(
                 body, mesh=self.mesh,
                 in_specs=(P(), P(), P(), pspec, pspec, pspec),
-                out_specs=(pspec, pspec) + (P(),) * (n_out - 2))(
+                out_specs=(pspec, pspec) + (P(),) * (n_out - 2),
+                check_vma=False)(
                     k_avail, k_sample, round_idx, last_round, participation,
                     self._synth_pad)
         if self.faults is None:

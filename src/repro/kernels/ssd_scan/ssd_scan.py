@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.utils.compat import tpu_compiler_params
-
 CHUNK = 128
 
 
@@ -95,7 +93,7 @@ def ssd_scan_kernel(x, dt, Bm, Cm, A, *, interpret: bool = True):
             jax.ShapeDtypeStruct((Bsz, H, p, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, Bm, Cm, A)

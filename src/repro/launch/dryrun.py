@@ -24,7 +24,6 @@ from repro.launch import steps as ST
 from repro.launch.mesh import make_production_mesh, mesh_config
 from repro.models import build
 from repro.sharding import specs as SP
-from repro.utils.compat import set_mesh
 
 RESULTS_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
 
@@ -98,7 +97,7 @@ def dryrun_one(arch: str, shape_name: str, multi_pod: bool,
     pspecs = SP.param_specs(params_sh, cfg, mcfg)
     inputs = ST.input_specs(cfg, shape)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt_sh = ST.opt_state_shape(params_sh)
             fn = ST.make_fed_train_step(model, DPConfig(

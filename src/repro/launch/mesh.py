@@ -36,7 +36,10 @@ def make_production_mesh(*, multi_pod: bool = False,
         raise ValueError(
             f"make_production_mesh: shape {shape} must have one entry per "
             f"axis {cfg.axes}")
-    return jax.make_mesh(shape, cfg.axes)
+    # the sharding rules (sharding.specs, launch.steps) are written for Auto
+    # axes; jax.make_mesh would otherwise make every axis Explicit
+    return jax.make_mesh(shape, cfg.axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def mesh_config(*, multi_pod: bool = False) -> MeshConfig:

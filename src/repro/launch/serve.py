@@ -26,6 +26,7 @@ from repro.models import build
 from repro.serve import NwpRequest, ServeEngine
 from repro.serve.sampling import sample_tokens
 from repro.train import checkpoint
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def generate(model, params, prompts: jnp.ndarray, steps: int,
@@ -70,7 +71,8 @@ def generate(model, params, prompts: jnp.ndarray, steps: int,
     return jnp.concatenate([prompts, jnp.stack(toks, axis=1)], axis=1)
 
 
-def main():
+def main(argv=None):
+    """Parse ``argv`` (default: ``sys.argv[1:]``) and serve the sessions."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gboard-cifg-lstm")
     ap.add_argument("--reduced", action="store_true")
@@ -86,7 +88,8 @@ def main():
                     help="base sampling seed (session i uses seed+i)")
     ap.add_argument("--top-k", type=int, default=3,
                     help="suggestion-strip candidates per position")
-    ap.add_argument("--vocab", type=int, default=2000)
+    ap.add_argument("--vocab", type=int, default=None,
+                    help="override the lstm vocab (default: the config's)")
     ap.add_argument("--hot-swap", default=None, metavar="CKPT",
                     help="promote this checkpoint mid-run (hot-swap demo)")
     ap.add_argument("--reference", action="store_true",
@@ -96,12 +99,13 @@ def main():
                     choices=["auto", "fused", "seq", "ref"],
                     help="lstm recurrence implementation (decode_step runs "
                          "the same fused Pallas cell as training)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    if cfg.family == "lstm":
+    if cfg.family == "lstm" and args.vocab is not None:
         cfg = cfg.with_(vocab=args.vocab)
     if args.cell_path is not None:
         cfg = cfg.with_(cell_path=args.cell_path)

@@ -25,14 +25,19 @@ from repro.fl.faults import FaultConfig
 from repro.fl.round import FederatedTrainer
 from repro.models import build
 from repro.train import checkpoint
+from repro.utils.compile_cache import enable_compile_cache
 
 
-def main():
+def main(argv=None):
+    """Parse ``argv`` (default: ``sys.argv[1:]``), train, checkpoint; returns
+    the :class:`FederatedTrainer` so in-process callers can read its
+    history."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gboard-cifg-lstm")
     ap.add_argument("--reduced", action="store_true",
                     help="use the smoke-scale variant of the arch")
-    ap.add_argument("--vocab", type=int, default=2000)
+    ap.add_argument("--vocab", type=int, default=None,
+                    help="override the lstm vocab (default: the config's)")
     ap.add_argument("--rounds", type=int, default=100)
     ap.add_argument("--n-users", type=int, default=300)
     ap.add_argument("--clients-per-round", type=int, default=40)
@@ -142,12 +147,13 @@ def main():
                     help="simulate a crash: exit (skipping the final "
                          "checkpoint) once this many rounds are done — for "
                          "exercising --resume")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    if cfg.family == "lstm":
+    if cfg.family == "lstm" and args.vocab is not None:
         cfg = cfg.with_(vocab=args.vocab)
     if args.cell_path is not None:
         cfg = cfg.with_(cell_path=args.cell_path)
@@ -249,7 +255,7 @@ def main():
         if args.crash_after is not None and done >= args.crash_after:
             print(f"simulated crash after round {done} "
                   f"(resume with --resume)")
-            return
+            return trainer
 
     committed = sum(r.get("committed", True)
                     for r in trainer.state.history)
@@ -265,6 +271,7 @@ def main():
     (out / f"{args.arch}_r{args.rounds}_history.json").write_text(
         json.dumps(trainer.state.history[-50:], indent=1))
     print(f"checkpoint: {ck}")
+    return trainer
 
 
 if __name__ == "__main__":

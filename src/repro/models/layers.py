@@ -198,9 +198,8 @@ def shard_hint(x, *spec):
     every named axis exists + divides — so model code can annotate hot
     activations (MoE dispatch, per-client grads) without coupling tests or
     CPU runs to a mesh."""
-    from repro.utils.compat import get_abstract_mesh
-    mesh = get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     names = dict(mesh.shape_tuple)
 

@@ -76,18 +76,18 @@ def _input_projection(params, x, cd):
 
 
 def _recurrence(params, zx, cfg: ModelConfig, remat: bool):
-    """Run the CIFG recurrence over zx (B, S, 3h) → (hs (B, S, h) f32,
-    (h_fin, c_fin)), dispatching on the resolved ``cell_path``."""
+    """Run the CIFG recurrence over zx (B, S, 3h) → hs (B, S, h) f32,
+    dispatching on the resolved ``cell_path``."""
     B = zx.shape[0]
     hidden = cfg.d_ff
     h0 = jnp.zeros((B, hidden), jnp.float32)
     c0 = jnp.zeros((B, hidden), jnp.float32)
     path = resolve_cell_path(cfg)
     if path in ("fused", "seq"):
-        hs, fin = cifg_sequence(zx.transpose(1, 0, 2), h0, c0,
-                                params["w_h"], cell=path,
-                                compute_dtype=cfg.compute_dtype, remat=remat)
-        return hs.transpose(1, 0, 2), fin
+        hs, _ = cifg_sequence(zx.transpose(1, 0, 2), h0, c0,
+                              params["w_h"], cell=path,
+                              compute_dtype=cfg.compute_dtype, remat=remat)
+        return hs.transpose(1, 0, 2)
 
     def step(carry, zx_t):
         h, c = cifg_cell_ref(zx_t, carry[0], carry[1], params["w_h"],
@@ -96,23 +96,19 @@ def _recurrence(params, zx, cfg: ModelConfig, remat: bool):
 
     if remat:
         step = jax.checkpoint(step)
-    (h_fin, c_fin), hs = jax.lax.scan(step, (h0, c0), zx.transpose(1, 0, 2))
-    return hs.transpose(1, 0, 2), (h_fin, c_fin)
+    _, hs = jax.lax.scan(step, (h0, c0), zx.transpose(1, 0, 2))
+    return hs.transpose(1, 0, 2)
 
 
-def forward(params, batch, cfg: ModelConfig, *, remat: bool = False,
-            collect_cache: bool = False):
+def forward(params, batch, cfg: ModelConfig, *, remat: bool = False):
     cd = jnp.dtype(cfg.compute_dtype)
     tokens = batch["tokens"]
     x = embed_tokens(params["embed"], tokens, cd)  # (B,S,d)
     zx = _input_projection(params, x, cd)          # (B,S,3h) — one GEMM
-    hs, (h_fin, c_fin) = _recurrence(params, zx, cfg, remat)
+    hs = _recurrence(params, zx, cfg, remat)
     hs = hs.astype(cd)                             # (B,S,hidden)
     y = hs @ params["w_proj"].astype(cd)           # (B,S,d)
-    logits = lm_logits(params["embed"], y)
-    if collect_cache:
-        return logits, (h_fin, c_fin)
-    return logits
+    return lm_logits(params["embed"], y)
 
 
 def loss_fn(params, batch, cfg: ModelConfig, *, remat: bool = False):
@@ -131,57 +127,76 @@ def init_cache(cfg: ModelConfig, batch_size: int, max_len: int):
             "pos": jnp.zeros((batch_size,), jnp.int32)}
 
 
+# XLA compiles a one-row matmul on the TPU differently from a multi-row
+# one, so a session's logits and state would depend on how many sessions
+# share its batch: a 1-row reference decode and a 16-slot engine tick differ
+# in the last bits. Serving batches are therefore padded to the 8-row
+# sublane tile, the chip's own row granularity, so that every row's
+# arithmetic is the same whatever the batch (the serving parity contract of
+# `repro.serve`).
+SERVE_ROWS = 8
+
+
+def _pad_rows(a, rows: int, value=0):
+    return jnp.pad(a, [(0, rows - a.shape[0])] + [(0, 0)] * (a.ndim - 1),
+                   constant_values=value)
+
+
+def _head(params, h, cd):
+    """Final state (B, h) → next-token logits (B, Vpad) f32."""
+    y = (h.astype(cd) @ params["w_proj"].astype(cd))[:, None, :]
+    return lm_logits(params["embed"], y)[:, 0, :]
+
+
 def prefill(params, batch, cfg: ModelConfig, *, max_len: int = None):
     """Prompt prefill → (last-position logits (B, V), decode cache).
 
     An optional ``batch["length"]`` ((B,) int32, 1 ≤ length ≤ S) marks each
     row's true prompt length inside right-padded ``tokens`` — the serving
-    engine's bucket-padded admission path. The recurrence is causal and the
-    hoisted input-projection GEMM is row-stable, so the state and logits
-    gathered at ``length - 1`` are bit-identical to an unpadded prefill of
-    exactly ``length`` tokens (tests/test_serve_engine.py pins this)."""
+    engine's bucket-padded admission path; without it every row is ``S``
+    long. The recurrence is causal and the hoisted input-projection GEMM is
+    row-stable over row-tile multiples, so the state and logits gathered at
+    ``length - 1`` are bit-identical to an unpadded prefill of exactly
+    ``length`` tokens (tests/test_serve_engine.py pins this)."""
     del max_len  # recurrent state — nothing to pad
-    if "length" not in batch:
-        logits, (h, c) = forward(params, batch, cfg, collect_cache=True)
-        B, S = batch["tokens"].shape
-        return logits[:, -1, :], {"h": h, "c": c,
-                                  "pos": jnp.full((B,), S, jnp.int32)}
     cd = jnp.dtype(cfg.compute_dtype)
     tokens = batch["tokens"]
-    length = jnp.asarray(batch["length"], jnp.int32)
-    B = tokens.shape[0]
-    x = embed_tokens(params["embed"], tokens, cd)
+    B, S = tokens.shape
+    length = (jnp.asarray(batch["length"], jnp.int32) if "length" in batch
+              else jnp.full((B,), S, jnp.int32))
+    rows = -(-B // SERVE_ROWS) * SERVE_ROWS
+    x = embed_tokens(params["embed"], _pad_rows(tokens, rows), cd)
     zx = _input_projection(params, x, cd)
-    h0 = jnp.zeros((B, cfg.d_ff), jnp.float32)
-    c0 = jnp.zeros((B, cfg.d_ff), jnp.float32)
-    # full (S, B, H) state stacks through the same per-step forward as
+    h0 = jnp.zeros((rows, cfg.d_ff), jnp.float32)
+    # full (S, rows, H) state stacks through the same per-step forward as
     # _recurrence ("seq"'s step IS the "ref" cell), gathered at length-1
     path = resolve_cell_path(cfg)
-    hs, cs = cifg_states(zx.transpose(1, 0, 2), h0, c0, params["w_h"],
+    hs, cs = cifg_states(zx.transpose(1, 0, 2), h0, h0, params["w_h"],
                          cell="fused" if path == "fused" else "seq",
                          compute_dtype=cfg.compute_dtype)
-    rows = jnp.arange(B)
-    h = hs[length - 1, rows]
-    c = cs[length - 1, rows]
-    y = (h.astype(cd) @ params["w_proj"].astype(cd))[:, None, :]
-    logits = lm_logits(params["embed"], y)[:, 0, :]
-    return logits, {"h": h, "c": c, "pos": length}
+    last = _pad_rows(length, rows, S) - 1
+    h = hs[last, jnp.arange(rows)]
+    c = cs[last, jnp.arange(rows)]
+    logits = _head(params, h, cd)
+    return logits[:B], {"h": h[:B], "c": c[:B], "pos": length}
 
 
 def decode_step(params, tokens, cache, cfg: ModelConfig):
     cd = jnp.dtype(cfg.compute_dtype)
-    x = embed_tokens(params["embed"], tokens[:, None], cd)[:, 0, :]
+    B = tokens.shape[0]
+    rows = -(-B // SERVE_ROWS) * SERVE_ROWS
+    h, c = _pad_rows(cache["h"], rows), _pad_rows(cache["c"], rows)
+    x = embed_tokens(params["embed"], _pad_rows(tokens, rows), cd)
     zx = (x @ params["w_x"].astype(cd)).astype(jnp.float32) \
         + params["b_gates"]
     if resolve_cell_path(cfg) == "fused":
-        h, c = cifg_step(zx, cache["h"], cache["c"], params["w_h"],
+        h, c = cifg_step(zx, h, c, params["w_h"],
                          compute_dtype=cfg.compute_dtype)
     else:
-        h, c = cifg_cell_ref(zx, cache["h"], cache["c"], params["w_h"],
+        h, c = cifg_cell_ref(zx, h, c, params["w_h"],
                              compute_dtype=cfg.compute_dtype)
-    y = (h.astype(cd) @ params["w_proj"].astype(cd))[:, None, :]
-    logits = lm_logits(params["embed"], y)[:, 0, :]
-    return logits, {"h": h, "c": c, "pos": cache["pos"] + 1}
+    logits = _head(params, h, cd)
+    return logits[:B], {"h": h[:B], "c": c[:B], "pos": cache["pos"] + 1}
 
 
 def build(cfg: ModelConfig) -> Model:

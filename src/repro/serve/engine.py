@@ -287,17 +287,20 @@ class ServeEngine:
         """Behavioral probe for the length-aware prefill contract: a model
         supports bucket-padded admission iff prefilling ``[t]`` unpadded and
         ``[t, 0]`` with ``length=[1]`` agree *bitwise* (logits and every
-        cache leaf). A model that rejects — or silently ignores — the
-        ``"length"`` batch key fails the probe, and admission falls back to
-        exact-length prefills (one jit compile per distinct prompt
-        length)."""
+        cache leaf). A model that rejects the ``"length"`` batch key (a
+        ``KeyError``, ``TypeError`` or ``ValueError`` naming it) or silently
+        ignores it fails the probe, and admission falls back to exact-length
+        prefills (one jit compile per distinct prompt length). Any other
+        error — a kernel the backend refuses, a runtime fault — propagates."""
+        ref_lg, ref_sub = self._prefill_j(self._params,
+                                          jnp.zeros((1, 1), jnp.int32))
         try:
-            toks = jnp.zeros((1, 1), jnp.int32)
-            ref_lg, ref_sub = self._prefill_j(self._params, toks)
             lg, sub = self._prefill_len_j(
                 self._params, jnp.zeros((1, 2), jnp.int32),
                 jnp.ones((1,), jnp.int32))
-        except Exception:
+        except (KeyError, TypeError, ValueError) as e:
+            if "length" not in str(e):
+                raise
             return False
         ref_leaves = jax.tree_util.tree_leaves((ref_lg, ref_sub))
         leaves = jax.tree_util.tree_leaves((lg, sub))

@@ -1,10 +1,18 @@
-"""Shared pytest config: the ``slow`` marker.
+"""Shared pytest config: the ``slow`` marker, and no compilation cache.
 
 Tier-1 (``PYTHONPATH=src python -m pytest -x -q``) must finish in well under
 two minutes, so anything heavier — full compile sweeps, long training runs —
 is marked ``@pytest.mark.slow`` and only runs with ``--runslow``.
+
+The entry points turn JAX's persistent compilation cache on
+(`repro.utils.compile_cache`); the tests keep it off, in this process and in
+the CLI subprocesses they start.
 """
+import os
+
 import pytest
+
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 
 def pytest_addoption(parser):
@@ -13,6 +21,8 @@ def pytest_addoption(parser):
 
 
 def pytest_configure(config):
+    import jax
+    jax.config.update("jax_enable_compilation_cache", False)
     config.addinivalue_line(
         "markers", "slow: heavy test (compile sweep / long training), "
                    "skipped unless --runslow is given")

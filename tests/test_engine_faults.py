@@ -23,7 +23,6 @@ Shard/pod cases need forced devices:
         PYTHONPATH=src python -m pytest -q tests/test_engine_faults.py
 """
 import hashlib
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -381,17 +380,12 @@ def test_restore_rejects_wrong_kind(setup, tmp_path):
 
 def _cli(tmp_path, extra):
     from repro.launch import train as train_cli
-    argv = ["train", "--reduced", "--vocab", "120", "--rounds", "6",
+    argv = ["--reduced", "--vocab", "120", "--rounds", "6",
             "--n-users", "40", "--clients-per-round", "8",
             "--noise-multiplier", "0.3", "--availability", "0.6",
             "--rounds-per-call", "2", "--seed", "0",
             "--out", str(tmp_path)] + extra
-    old = sys.argv
-    sys.argv = argv
-    try:
-        train_cli.main()
-    finally:
-        sys.argv = old
+    train_cli.main(argv)
     ck = tmp_path / "gboard-cifg-lstm_r6.msgpack"
     return hashlib.sha256(ck.read_bytes()).hexdigest() if ck.exists() \
         else None

@@ -242,15 +242,13 @@ def test_eval_hook_under_sharding(setup, num_shards):
 
 
 @pytest.mark.slow
-def test_checkpoint_byte_parity_across_pods_and_shards(tmp_path,
-                                                      monkeypatch):
+def test_checkpoint_byte_parity_across_pods_and_shards(tmp_path):
     """End to end through the real CLI: `launch/train.py` runs with every
     {pods 1, 2} × {shards 1, 4} topology must write byte-identical
     checkpoints (sha256 over the .msgpack) — the strongest statement that
     the DP mechanism a launch ships is independent of the mesh it trained
     on."""
     import hashlib
-    import sys
     from repro.launch import train as train_cli
 
     if len(jax.devices()) < 8:
@@ -260,14 +258,13 @@ def test_checkpoint_byte_parity_across_pods_and_shards(tmp_path,
     digests = {}
     for pods, shards in ((1, 1), (1, 4), (2, 1), (2, 4)):
         out = tmp_path / f"p{pods}s{shards}"
-        argv = ["train", "--arch", "gboard-cifg-lstm", "--reduced",
+        argv = ["--arch", "gboard-cifg-lstm", "--reduced",
                 "--vocab", "64", "--rounds", "2", "--n-users", "40",
                 "--clients-per-round", "8", "--noise-multiplier", "0.25",
                 "--seq-len", "8", "--rounds-per-call", "2",
                 "--num-pods", str(pods), "--num-shards", str(shards),
                 "--seed", "0", "--out", str(out)]
-        monkeypatch.setattr(sys, "argv", argv)
-        train_cli.main()
+        train_cli.main(argv)
         (ck,) = out.glob("*.msgpack")
         digests[(pods, shards)] = hashlib.sha256(ck.read_bytes()).hexdigest()
     assert len(set(digests.values())) == 1, digests

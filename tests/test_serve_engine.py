@@ -262,6 +262,66 @@ def test_cache_layout_contract_rejected(lstm):
                for leaf in jax.tree_util.tree_leaves(cache))
 
 
+def test_full_width_rows_independent_of_batch():
+    """At the paper model's widths (embedding 96, hidden 256, vocab 10,000)
+    a session's prefill and decode results are bitwise independent of the
+    batch it shares and of bucket padding — what bucketed admission and
+    the reference parity rest on."""
+    model = build(get_config("gboard-cifg-lstm"))
+    params = model.init(jax.random.PRNGKey(0))
+    assert ServeEngine(model, params, max_slots=16).bucketed_admission
+    toks = jax.random.randint(jax.random.PRNGKey(1), (16, 5), 4, 10_000)
+    exact = jax.jit(model.prefill)(params, {"tokens": toks[:1, :3]})
+    padded = jax.jit(model.prefill)(
+        params, {"tokens": toks[:1, :4], "length": jnp.array([3])})
+    logits, cache = jax.jit(model.prefill)(params, {"tokens": toks})
+    one = jax.jit(model.decode_step)(params, toks[:1, 0],
+                                     jax.tree_util.tree_map(
+                                         lambda a: a[:1], cache))
+    full = jax.jit(model.decode_step)(params, toks[:, 0], cache)
+    for a, b in ((exact, padded), (one, jax.tree_util.tree_map(
+            lambda x: x[:1], full))):
+        for x, y in zip(jax.tree_util.tree_leaves(a),
+                        jax.tree_util.tree_leaves(b)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def _with_length_prefill(model, error):
+    """``model`` whose length-aware prefill raises ``error`` instead."""
+    def prefill(params, batch, **kw):
+        if "length" in batch:
+            raise error
+        return model.prefill(params, batch, **kw)
+    return model._replace(prefill=prefill)
+
+
+def test_length_probe_falls_back_on_rejected_key(lstm):
+    """A model that rejects the ``length`` batch key is served with
+    exact-length admission, token for token like the reference."""
+    model, params = lstm
+    plain = _with_length_prefill(
+        model, KeyError("unsupported batch key 'length'"))
+    eng = ServeEngine(plain, params, max_slots=2, top_k=3)
+    assert not eng.bucketed_admission
+    assert ServeEngine(model, params, max_slots=2).bucketed_admission
+    reqs = _requests(np.random.default_rng(3), 3)
+    sids = [eng.submit(r) for r in reqs]
+    eng.run()
+    _assert_matches_reference(model, params, eng, reqs, sids)
+
+
+def test_length_probe_propagates_other_errors(lstm):
+    """Only a rejection of the ``length`` key is a fallback: a compile or
+    runtime failure of the length-aware prefill surfaces at construction."""
+    model, params = lstm
+    broken = _with_length_prefill(model, RuntimeError("kernel refused"))
+    with pytest.raises(RuntimeError, match="kernel refused"):
+        ServeEngine(broken, params, max_slots=2)
+    unrelated = _with_length_prefill(model, ValueError("bad tile shape"))
+    with pytest.raises(ValueError, match="bad tile shape"):
+        ServeEngine(unrelated, params, max_slots=2)
+
+
 def test_engine_constructor_validation(lstm):
     model, params = lstm
     with pytest.raises(ValueError, match="max_slots"):

@@ -10,9 +10,9 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import (ASSIGNED_ARCHS, INPUT_SHAPES, MULTI_POD,
                            SINGLE_POD, get_config)
+from repro.launch.mesh import make_production_mesh
 from repro.models import build
 from repro.sharding import specs as SP
-from repro.utils import compat
 
 AX = dict(zip(SINGLE_POD.axes, SINGLE_POD.shape))
 AX_MP = dict(zip(MULTI_POD.axes, MULTI_POD.shape))
@@ -87,12 +87,12 @@ def test_fed_train_step_compiles_1x1(arch):
 
     cfg = get_config(arch).reduced()
     model = build(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_production_mesh(shape=(1, 1))
     mcfg = MeshConfig((1, 1), ("data", "model"))
     shape = InputShape("tiny_train", 16, 4, "train")
     params_sh = ST.params_shape(model)
     pspecs = SP.param_specs(params_sh, cfg, mcfg)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn = ST.make_fed_train_step(model, DPConfig(clients_per_round=4),
                                     mesh, mcfg, pspecs, shape, donate=False)
         opt_sh = ST.opt_state_shape(params_sh)
@@ -112,14 +112,14 @@ def test_fed_step_numerics():
 
     cfg = get_config("granite-3-2b").reduced()
     model = build(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_production_mesh(shape=(1, 1))
     mcfg = MeshConfig((1, 1), ("data", "model"))
     shape = InputShape("tiny_train", 16, 4, "train")
     params = model.init(jax.random.PRNGKey(0))
     pspecs = SP.param_specs(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
                             cfg, mcfg)
     dp = DPConfig(clients_per_round=4, noise_multiplier=0.1, clip_norm=0.5)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn = ST.make_fed_train_step(model, dp, mesh, mcfg, pspecs, shape,
                                     donate=False)
         key = jax.random.PRNGKey(1)
