@@ -42,12 +42,13 @@ import time
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent
-sys.path.insert(0, str(ROOT / "src"))
+sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from bench.harness.clock import CompileCounter  # noqa: E402
 from repro.configs import ClientConfig, DPConfig, get_config  # noqa: E402
 from repro.core.clipping import clip_accumulate_tree  # noqa: E402
 from repro.data.corpus import BigramCorpus  # noqa: E402
@@ -83,19 +84,6 @@ DEPLOY_USERS = 10 ** 6
 DEPLOY_BASE = 1000
 DEPLOY_COHORT = 1000
 DEPLOY_GOAL = 800
-
-
-class _CompileClock:
-    """Seconds the XLA backend spends compiling, summed from JAX's own
-    monitoring events."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self)
-
-    def __call__(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
 
 
 @contextlib.contextmanager
@@ -322,7 +310,7 @@ def main(argv=None) -> None:
     print(f"device: kind={dev.device_kind} count={len(devices)} "
           f"jax={jax.__version__}", flush=True)
     print(f"compilation cache: {enable_compile_cache()}", flush=True)
-    clock = _CompileClock()
+    clock = CompileCounter()
     out = Path(args.out)
     out.mkdir(parents=True, exist_ok=True)
     if args.four_chips:

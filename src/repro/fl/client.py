@@ -41,6 +41,7 @@ from repro.fl.reduction import (CANON_BLOCKS, canon_pad, fold_blocks,
                                 resolve_chunk)
 from repro.models.api import Model
 from repro.utils.pytree import tree_sub, tree_zeros_like
+from repro.utils.spans import scope
 
 
 def local_sgd(model: Model, params, batches: Dict[str, jnp.ndarray],
@@ -149,7 +150,8 @@ def chunk_accumulate(acc, deltas, losses, mask, clip_norm: float, *,
         stats = stats + jnp.stack([norm * mi, flag * mi, loss * mi, mi])
         return (upd, stats), None
 
-    (upd, stats), _ = jax.lax.scan(fold, acc, (deltas, losses, m))
+    with scope("clip_fold"):
+        (upd, stats), _ = jax.lax.scan(fold, acc, (deltas, losses, m))
     return upd, stats
 
 

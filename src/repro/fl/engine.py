@@ -179,6 +179,7 @@ from repro.launch.mesh import make_cohort_mesh
 from repro.models.api import Model
 from repro.sharding.specs import (batch_axes, cohort_spec,
                                   sim_mesh_config)
+from repro.utils.spans import scope, span
 
 __all__ = ["CANON_BLOCKS", "EngineState", "FaultConfig",
            "POPULATION_BACKENDS", "SAMPLERS", "SimEngine", "canon_pad",
@@ -711,8 +712,10 @@ class SimEngine:
 
         if corrupt is None:
             def compute_chunk(inputs):
-                batches = self._gather_batches(inputs)
-                return local_deltas(self.model, params, batches, self.client)
+                with scope("client_sgd"):
+                    batches = self._gather_batches(inputs)
+                    return local_deltas(self.model, params, batches,
+                                        self.client)
 
             inputs, guard = args_r, False
         else:
@@ -720,9 +723,10 @@ class SimEngine:
 
             def compute_chunk(inputs):
                 args, bad = inputs
-                batches = self._gather_batches(args)
-                deltas, losses = local_deltas(self.model, params, batches,
-                                              self.client)
+                with scope("client_sgd"):
+                    batches = self._gather_batches(args)
+                    deltas, losses = local_deltas(self.model, params,
+                                                  batches, self.client)
                 # multiply by 1 (clean) or NaN (corrupt): x·1 is a bitwise
                 # identity, x·NaN wrecks every element — the guard must
                 # reject the whole report, not salvage parts of it
@@ -783,8 +787,9 @@ class SimEngine:
             tree, scal = self._local_block_sums(params, batch_args,
                                                 slot_mask, self.n_blocks,
                                                 corrupt)
-            return (jax.tree_util.tree_map(_fold_blocks, tree),
-                    _fold_blocks(scal))
+            with scope("clip_fold"):
+                return (jax.tree_util.tree_map(_fold_blocks, tree),
+                        _fold_blocks(scal))
 
         cspec = self._cohort_pspec
         axes = batch_axes(self._mesh_config)  # ("data",) or ("pod", "data")
@@ -796,27 +801,29 @@ class SimEngine:
             tree, scal = self._local_block_sums(params, batch_args,
                                                 slot_mask, nblk_local,
                                                 corrupt)
-            # all_gather carries the raw block partials (no arithmetic), so
-            # the pairwise tree below is evaluated identically — and with
-            # the identical association — on every shard. The cohort layout
-            # is pod-major, so gathering over the data axis yields this
-            # pod's contiguous block group in canonical order.
-            gather_d = lambda l: jax.lax.all_gather(l, data_axis).reshape(
-                (nblk_pod,) + l.shape[1:])
-            if self.num_pods == 1:
-                tree = jax.tree_util.tree_map(gather_d, tree)
-                return (jax.tree_util.tree_map(_fold_blocks, tree),
-                        _fold_blocks(gather_d(scal)))
-            # pod-local fold first: only the folded pod partials — one
-            # |params|-sized value per pod, not per block — cross the
-            # expensive inter-pod links
-            pod_tree = jax.tree_util.tree_map(
-                lambda l: _fold_blocks(gather_d(l)), tree)
-            pod_scal = _fold_blocks(gather_d(scal))
-            gather_p = lambda l: jax.lax.all_gather(l, "pod")
-            tree = jax.tree_util.tree_map(
-                lambda l: _fold_blocks(gather_p(l)), pod_tree)
-            return tree, _fold_blocks(gather_p(pod_scal))
+            with scope("clip_fold"):
+                # all_gather carries the raw block partials (no
+                # arithmetic), so the pairwise tree below is evaluated
+                # identically — and with the identical association — on
+                # every shard. The cohort layout is pod-major, so gathering
+                # over the data axis yields this pod's contiguous block
+                # group in canonical order.
+                gather_d = lambda l: jax.lax.all_gather(l, data_axis).reshape(
+                    (nblk_pod,) + l.shape[1:])
+                if self.num_pods == 1:
+                    tree = jax.tree_util.tree_map(gather_d, tree)
+                    return (jax.tree_util.tree_map(_fold_blocks, tree),
+                            _fold_blocks(gather_d(scal)))
+                # pod-local fold first: only the folded pod partials — one
+                # |params|-sized value per pod, not per block — cross the
+                # expensive inter-pod links
+                pod_tree = jax.tree_util.tree_map(
+                    lambda l: _fold_blocks(gather_d(l)), tree)
+                pod_scal = _fold_blocks(gather_d(scal))
+                gather_p = lambda l: jax.lax.all_gather(l, "pod")
+                tree = jax.tree_util.tree_map(
+                    lambda l: _fold_blocks(gather_p(l)), pod_tree)
+                return tree, _fold_blocks(gather_p(pod_scal))
 
         # cspec is a pytree *prefix*: it shards every batch_args leaf along
         # its leading cohort-slot axis, whatever the backend's tuple layout.
@@ -1023,12 +1030,14 @@ class SimEngine:
         # or the report goal under the fault model, never the realized
         # survivor count. The noise key is the replicated stream: one draw,
         # every shard agrees.
-        delta, stats = finalize_round(total, self._round_denom, k_noise,
-                                      self.dp,
-                                      stats=(mean_norm, frac_clipped))
+        with scope("server_step"):
+            delta, stats = finalize_round(total, self._round_denom, k_noise,
+                                          self.dp,
+                                          stats=(mean_norm, frac_clipped))
         if self.faults is None:
-            params, opt_state = server_step(params, opt_state, delta,
-                                            self.dp)
+            with scope("server_step"):
+                params, opt_state = server_step(params, opt_state, delta,
+                                                self.dp)
             rec = {"loss": loss, "mean_update_norm": mean_norm,
                    "frac_clipped": frac_clipped,
                    "noise_std": stats.noise_std, "n_clients": n_selected}
@@ -1039,11 +1048,12 @@ class SimEngine:
             n_accepted = scal[3].astype(jnp.int32)
             n_reported = jnp.sum(report_mask).astype(jnp.int32)
             committed = scal[3] >= jnp.float32(self.report_goal)
-            params, opt_state = jax.lax.cond(
-                committed,
-                lambda po: server_step(po[0], po[1], delta, self.dp),
-                lambda po: po,
-                (params, opt_state))
+            with scope("server_step"):
+                params, opt_state = jax.lax.cond(
+                    committed,
+                    lambda po: server_step(po[0], po[1], delta, self.dp),
+                    lambda po: po,
+                    (params, opt_state))
             rec = {"loss": loss, "mean_update_norm": mean_norm,
                    "frac_clipped": frac_clipped,
                    "noise_std": stats.noise_std, "n_clients": n_accepted,
@@ -1062,10 +1072,11 @@ class SimEngine:
 
     def _round_body(self, state: EngineState, _=None
                     ) -> Tuple[EngineState, Dict[str, jax.Array]]:
-        key, last_round, participation, \
-            (ids, slot_mask, report_mask, corrupt, keys, k_noise) = \
-            self._sample_phase(state.key, state.last_round,
-                               state.participation, state.round_idx)
+        with scope("sample"):
+            key, last_round, participation, \
+                (ids, slot_mask, report_mask, corrupt, keys, k_noise) = \
+                self._sample_phase(state.key, state.last_round,
+                                   state.participation, state.round_idx)
         params, opt_state, rec = self._compute_phase(
             state.params, state.opt_state, state.round_idx, (ids, keys),
             slot_mask, report_mask, corrupt, k_noise)
@@ -1092,10 +1103,11 @@ class SimEngine:
         state plus everything the host needs to stage the cohort: ``(ids,
         slot/report/corrupt masks, per-slot keys, k_noise, this round's
         index)``."""
-        key, last_round, participation, \
-            (ids, slot_mask, report_mask, corrupt, keys, k_noise) = \
-            self._sample_phase(sstate.key, sstate.last_round,
-                               sstate.participation, sstate.round_idx)
+        with scope("sample"):
+            key, last_round, participation, \
+                (ids, slot_mask, report_mask, corrupt, keys, k_noise) = \
+                self._sample_phase(sstate.key, sstate.last_round,
+                                   sstate.participation, sstate.round_idx)
         new = _SamplerState(key, last_round, participation,
                             sstate.round_idx + 1)
         return new, (ids, slot_mask, report_mask, corrupt, keys, k_noise,
@@ -1126,18 +1138,21 @@ class SimEngine:
                         donate_argnums=(0, 1) if donate else ()))
         return self._streamed_jits[donate]
 
-    def _stage_cohort(self, ids: np.ndarray, slot: int):
+    def _stage_cohort(self, ids: np.ndarray, slot: int, round_idx: int):
         """Host-gather one cohort's example rows from the PopulationStore
         and start their host→device transfer into buffer ``slot`` (two slots
         ping-pong so at most two staged cohorts are ever device-live — the
-        one computing and the one prefetching)."""
-        ex = self.store.gather(ids)
-        cnt = self.store.gather_counts(ids)
-        if self._cohort_sharding is not None:
-            staged = (jax.device_put(ex, self._cohort_sharding),
-                      jax.device_put(cnt, self._cohort_sharding))
-        else:
-            staged = (jax.device_put(ex), jax.device_put(cnt))
+        one computing and the one prefetching). ``round_idx`` labels the
+        spans."""
+        with span("fl.gather", round=round_idx):
+            ex = self.store.gather(ids)
+            cnt = self.store.gather_counts(ids)
+        with span("fl.put", round=round_idx):
+            if self._cohort_sharding is not None:
+                staged = (jax.device_put(ex, self._cohort_sharding),
+                          jax.device_put(cnt, self._cohort_sharding))
+            else:
+                staged = (jax.device_put(ex), jax.device_put(cnt))
         self._inflight[slot] = staged   # overwriting frees round k−2's pair
         return staged
 
@@ -1152,15 +1167,21 @@ class SimEngine:
         stage-then-compute sequentially (the reference dispatch order);
         both orders consume identical PRNG streams and are bit-identical."""
         sample_jit, compute_jit = self._streamed_fns(donate)
+        # the host's copy of the first round's index, for the spans (the
+        # sampler state is donated below)
+        r0 = int(state.round_idx)
         sstate = _SamplerState(state.key, state.last_round,
                                state.participation, state.round_idx)
         params, opt_state = state.params, state.opt_state
 
-        def sample_and_stage(sstate, slot):
-            sstate, (ids, slot_mask, report_mask, corrupt, keys,
-                     k_noise, ridx) = sample_jit(sstate)
+        def sample_and_stage(sstate, r):
+            with span("fl.sample", round=r0 + r):
+                sstate, (ids, slot_mask, report_mask, corrupt, keys,
+                         k_noise, ridx) = sample_jit(sstate)
             # the only per-round host sync: the (padded,) id vector
-            ex, cnt = self._stage_cohort(np.asarray(ids), slot)
+            with span("fl.ids_wait", round=r0 + r):
+                ids = np.asarray(ids)
+            ex, cnt = self._stage_cohort(ids, r % 2, r0 + r)
             return sstate, (ridx, ex, cnt, slot_mask, report_mask, corrupt,
                             keys, k_noise)
 
@@ -1168,19 +1189,22 @@ class SimEngine:
         if prefetch:
             sstate, staged = sample_and_stage(sstate, 0)
             for r in range(n_rounds):
-                params, opt_state, rec = compute_jit(params, opt_state,
-                                                     *staged)
+                with span("fl.compute", round=r0 + r):
+                    params, opt_state, rec = compute_jit(params, opt_state,
+                                                         *staged)
                 if r + 1 < n_rounds:
                     # overlaps the compute dispatched just above
-                    sstate, staged = sample_and_stage(sstate, (r + 1) % 2)
+                    sstate, staged = sample_and_stage(sstate, r + 1)
                 recs.append(rec)
         else:
             for r in range(n_rounds):
-                sstate, staged = sample_and_stage(sstate, r % 2)
-                params, opt_state, rec = compute_jit(params, opt_state,
-                                                     *staged)
+                sstate, staged = sample_and_stage(sstate, r)
+                with span("fl.compute", round=r0 + r):
+                    params, opt_state, rec = compute_jit(params, opt_state,
+                                                         *staged)
                 recs.append(rec)
-        recs = jax.device_get(recs)
+        with span("fl.history", round=r0 + n_rounds - 1):
+            recs = jax.device_get(recs)
         hist = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *recs)
         self._inflight = [None, None]
         new_state = EngineState(params, opt_state, sstate.key,

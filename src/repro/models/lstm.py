@@ -39,6 +39,7 @@ from repro.kernels.cifg_cell import (cifg_cell_ref, cifg_sequence,
 from repro.models import layers as L
 from repro.models.api import Model
 from repro.models.embed import embed_tokens, embedding_init, lm_logits
+from repro.utils.spans import scope
 
 CELL_PATHS = ("auto", "fused", "seq", "ref")
 
@@ -108,12 +109,15 @@ def forward(params, batch, cfg: ModelConfig, *, remat: bool = False):
     hs = _recurrence(params, zx, cfg, remat)
     hs = hs.astype(cd)                             # (B,S,hidden)
     y = hs @ params["w_proj"].astype(cd)           # (B,S,d)
-    return lm_logits(params["embed"], y)
+    with scope("head"):
+        return lm_logits(params["embed"], y)
 
 
 def loss_fn(params, batch, cfg: ModelConfig, *, remat: bool = False):
     logits = forward(params, batch, cfg, remat=remat)
-    return L.lm_loss(logits, batch["labels"], cfg.vocab, batch.get("mask"))
+    with scope("head"):
+        return L.lm_loss(logits, batch["labels"], cfg.vocab,
+                         batch.get("mask"))
 
 
 def init_cache(cfg: ModelConfig, batch_size: int, max_len: int):

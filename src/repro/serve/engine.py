@@ -49,6 +49,7 @@ rejected with a clear error.
 """
 from __future__ import annotations
 
+import collections
 import time
 from typing import Dict, List, Optional
 
@@ -61,6 +62,11 @@ from repro.serve import sampling
 from repro.serve.frontend import (NwpRequest, RequestQueue, SessionResult,
                                   _Session, make_session_key, new_session_id)
 from repro.train import checkpoint as checkpoint_lib
+from repro.utils.spans import span
+
+# admission times kept (the newest), far more than any measuring window
+# holds, so a long-lived server's list stays bounded
+ADMISSION_TIMES_KEPT = 1 << 16
 
 
 def validate_cache_layout(model: Model, max_slots: int, max_len: int):
@@ -126,6 +132,7 @@ class ServeEngine:
         self._results: Dict[str, SessionResult] = {}
         self._ticks = 0          # step() calls (admission opportunities)
         self._decode_ticks = 0   # ticks that actually ran a decode batch
+        self._decode_rows = 0    # live slots summed over the decode ticks
 
         vocab, K = self.vocab, self.top_k
 
@@ -160,7 +167,8 @@ class ServeEngine:
         # admission latency per admitted session (includes the prefill jit
         # compile on a fresh *bucketed* length — the long tail bucketing
         # exists to bound); bench_serve.py reports p50/p99 from this
-        self._admission_times: List[float] = []
+        self._admission_times: collections.deque = collections.deque(
+            maxlen=ADMISSION_TIMES_KEPT)
         self._bucketed = self._probe_length_support()
 
     # ------------------------------------------------------------- frontend
@@ -185,6 +193,17 @@ class ServeEngine:
     @property
     def ticks(self) -> int:
         return self._ticks
+
+    @property
+    def decode_ticks(self) -> int:
+        """Ticks that ran the decode program (some slot was live)."""
+        return self._decode_ticks
+
+    @property
+    def decode_rows(self) -> int:
+        """Live slots summed over the decode ticks; over
+        ``decode_ticks × max_slots`` it is the tick's occupancy."""
+        return self._decode_rows
 
     def submit(self, request: NwpRequest) -> str:
         """Validate + enqueue a session; returns its session id. A
@@ -244,27 +263,39 @@ class ServeEngine:
                 break
             if self._slots[slot] is None:
                 self._admit(slot, self._queue.pop())
-        if self.active_sessions == 0:
+        live = self.active_sessions
+        if live == 0:
             return len(self._queue) > 0
         self._decode_ticks += 1
-        nxt, cands, self._cache = self._tick_j(
-            self._params, self._cache,
-            jnp.asarray(self._cur_tok), jnp.asarray(self._keys),
-            jnp.asarray(self._ts), jnp.asarray(self._temps))
-        nxt = np.asarray(nxt)
-        cands = np.asarray(cands)
-        for slot, sess in enumerate(self._slots):
-            if sess is None:
-                continue
-            self._record_token(sess, int(nxt[slot]), cands[slot])
-            self._cur_tok[slot] = nxt[slot]
-            self._ts[slot] += 1
-            sess.ticks_in_slot += 1
-            if len(sess.tokens) >= sess.request.steps:
-                self._finalize(sess, "done", slot=slot)
-            elif self._ttl(sess) and sess.ticks_in_slot >= self._ttl(sess):
-                self._finalize(sess, "evicted", slot=slot)
+        self._decode_rows += live
+        with span("serve.tick", tick=self._decode_ticks, rows=live,
+                  slots=self.max_slots):
+            self._decode()
         return self.in_flight > 0
+
+    def _decode(self) -> None:
+        """One batched decode step over all slots, and its bookkeeping."""
+        with span("serve.upload"):
+            control = (jnp.asarray(self._cur_tok), jnp.asarray(self._keys),
+                       jnp.asarray(self._ts), jnp.asarray(self._temps))
+        nxt, cands, self._cache = self._tick_j(self._params, self._cache,
+                                               *control)
+        with span("serve.tick.sync"):
+            nxt = np.asarray(nxt)
+            cands = np.asarray(cands)
+        with span("serve.tick.book"):
+            for slot, sess in enumerate(self._slots):
+                if sess is None:
+                    continue
+                self._record_token(sess, int(nxt[slot]), cands[slot])
+                self._cur_tok[slot] = nxt[slot]
+                self._ts[slot] += 1
+                sess.ticks_in_slot += 1
+                if len(sess.tokens) >= sess.request.steps:
+                    self._finalize(sess, "done", slot=slot)
+                elif (self._ttl(sess)
+                      and sess.ticks_in_slot >= self._ttl(sess)):
+                    self._finalize(sess, "evicted", slot=slot)
 
     def run(self, max_ticks: int = 100_000) -> Dict[str, SessionResult]:
         """Drain queue + slots; returns {session_id: result} for every
@@ -329,26 +360,38 @@ class ServeEngine:
         token identical to the exact-length prefill, which is what
         :meth:`_probe_length_support` guarantees up front."""
         t0 = time.perf_counter()
+        sess.queue_s = t0 - sess.submit_time
         raw = np.asarray(sess.request.prompt, np.int32)
         L = int(raw.shape[0])
-        if self._bucketed and L > 1:
-            Lp = 1 << (L - 1).bit_length()
-            padded = np.zeros((1, Lp), np.int32)
-            padded[0, :L] = raw
-            lg, sub = self._prefill_len_j(self._params, jnp.asarray(padded),
-                                          jnp.full((1,), L, jnp.int32))
-        else:
-            lg, sub = self._prefill_j(self._params,
-                                      jnp.asarray(raw)[None, :])
-        tok0, cands0 = self._admission_sample_j(
-            lg, jnp.asarray(sess.key),
-            jnp.asarray(sess.request.temperature, jnp.float32))
-        self._cache = self._admit_j(self._cache, jnp.asarray(slot), sub)
-        sess.admit_tick = self._ticks
-        self._slots[slot] = sess
-        self._keys[slot] = sess.key
-        self._temps[slot] = sess.request.temperature
-        self._record_token(sess, int(tok0), np.asarray(cands0))
+        bucketed = self._bucketed and L > 1
+        with span("serve.admit", session=sess.session_id,
+                  queue_s=sess.queue_s):
+            with span("serve.upload"):
+                if bucketed:
+                    padded = np.zeros((1, 1 << (L - 1).bit_length()),
+                                      np.int32)
+                    padded[0, :L] = raw
+                    toks = jnp.asarray(padded)
+                    length = jnp.full((1,), L, jnp.int32)
+                else:
+                    toks = jnp.asarray(raw)[None, :]
+                key = jnp.asarray(sess.key)
+                temp = jnp.asarray(sess.request.temperature, jnp.float32)
+                slot_d = jnp.asarray(slot)
+            with span("serve.prefill"):
+                if bucketed:
+                    lg, sub = self._prefill_len_j(self._params, toks, length)
+                else:
+                    lg, sub = self._prefill_j(self._params, toks)
+                tok0, cands0 = self._admission_sample_j(lg, key, temp)
+                self._cache = self._admit_j(self._cache, slot_d, sub)
+            sess.admit_tick = self._ticks
+            self._slots[slot] = sess
+            self._keys[slot] = sess.key
+            self._temps[slot] = sess.request.temperature
+            with span("serve.admit.sync"):
+                tok0, cands0 = int(tok0), np.asarray(cands0)
+            self._record_token(sess, tok0, cands0)
         self._admission_times.append(time.perf_counter() - t0)
         self._cur_tok[slot] = sess.tokens[-1]
         self._ts[slot] = 1
@@ -379,6 +422,7 @@ class ServeEngine:
             submit_tick=sess.submit_tick,
             admit_tick=sess.admit_tick,
             finish_tick=self._ticks,
-            latency_s=time.perf_counter() - sess.submit_time)
+            latency_s=time.perf_counter() - sess.submit_time,
+            queue_s=sess.queue_s)
         self._results[sess.session_id] = res
         self._completed.append(res)

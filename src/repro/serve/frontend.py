@@ -80,7 +80,8 @@ class SessionResult:
     submit_tick: int
     admit_tick: int
     finish_tick: int
-    latency_s: float
+    latency_s: float                  # submit → finish
+    queue_s: float = 0.0              # submit → start of admission
 
     @property
     def sequence(self) -> Tuple[int, ...]:
@@ -99,6 +100,7 @@ class _Session:
     candidates: list = field(default_factory=list)
     versions: list = field(default_factory=list)
     admit_tick: int = -1
+    queue_s: float = 0.0
     ticks_in_slot: int = 0
 
 
