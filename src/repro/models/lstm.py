@@ -10,18 +10,27 @@ split into ``w_x (d, 3h)`` and ``w_h (h, 3h)`` so the input projection for
 *all* timesteps is one large ``(B·S, d) @ (d, 3h)`` GEMM hoisted out of the
 time scan (it is h-independent); the scan step only does the small
 ``h @ w_h`` matmul plus the gate nonlinearities and state update.
-``cfg.cell_path`` selects the recurrence implementation:
+``cfg.cell_path`` selects the implementation of the recurrence and of the
+training loss's output head; ``"fused"`` means the Pallas kernels of this
+model:
 
 * ``"fused"`` — `kernels.cifg_cell.cifg_sequence` with the Pallas cell
   kernel as the per-step forward (compiled on TPU, interpreter elsewhere)
   and the time-fused custom backward (gate recompute + ``dw_h`` reduction
-  batched over time outside the reverse scan);
+  batched over time outside the reverse scan); and ``loss_fn``'s head is
+  `kernels.lm_head_xent`: the tied-embedding logits, the softmax
+  cross-entropy and its backward in one kernel pair, so the (rows, Vpad)
+  f32 logits never leave VMEM. ``forward`` (eval, secret-sharer scoring)
+  and serving keep the plain `lm_logits` head;
 * ``"seq"`` — the same time-fused sequence op with the pure-jnp cell as
   the per-step forward (the fast path on non-TPU backends, where the
   Pallas interpreter would run the cell per step);
 * ``"ref"`` — the pre-split-style plain ``lax.scan`` over the jnp cell
   with ordinary jax autodiff through the scan — the validated reference;
-* ``"auto"`` (default) — ``"fused"`` on TPU, ``"seq"`` elsewhere.
+  ``"seq"`` and ``"ref"`` take the head's loss as `lm_logits` followed by
+  `layers.lm_loss` under autodiff;
+* ``"auto"`` (default) — ``"fused"`` on TPU (recurrence and head),
+  ``"seq"`` elsewhere.
 
 Old ``w_gates`` checkpoints load through the one-shot migration shim in
 `repro.train.checkpoint`.
@@ -36,6 +45,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.kernels.cifg_cell import (cifg_cell_ref, cifg_sequence,
                                      cifg_states, cifg_step)
+from repro.kernels.lm_head_xent.ops import lm_head_xent
 from repro.models import layers as L
 from repro.models.api import Model
 from repro.models.embed import embed_tokens, embedding_init, lm_logits
@@ -101,23 +111,31 @@ def _recurrence(params, zx, cfg: ModelConfig, remat: bool):
     return hs.transpose(1, 0, 2)
 
 
-def forward(params, batch, cfg: ModelConfig, *, remat: bool = False):
+def _features(params, batch, cfg: ModelConfig, remat: bool):
+    """Tokens → the head's input ``y`` (B, S, d) in the compute dtype."""
     cd = jnp.dtype(cfg.compute_dtype)
-    tokens = batch["tokens"]
-    x = embed_tokens(params["embed"], tokens, cd)  # (B,S,d)
+    x = embed_tokens(params["embed"], batch["tokens"], cd)  # (B,S,d)
     zx = _input_projection(params, x, cd)          # (B,S,3h) — one GEMM
     hs = _recurrence(params, zx, cfg, remat)
     hs = hs.astype(cd)                             # (B,S,hidden)
-    y = hs @ params["w_proj"].astype(cd)           # (B,S,d)
+    return hs @ params["w_proj"].astype(cd)        # (B,S,d)
+
+
+def forward(params, batch, cfg: ModelConfig, *, remat: bool = False):
+    y = _features(params, batch, cfg, remat)
     with scope("head"):
         return lm_logits(params["embed"], y)
 
 
 def loss_fn(params, batch, cfg: ModelConfig, *, remat: bool = False):
-    logits = forward(params, batch, cfg, remat=remat)
+    y = _features(params, batch, cfg, remat)
     with scope("head"):
-        return L.lm_loss(logits, batch["labels"], cfg.vocab,
-                         batch.get("mask"))
+        if resolve_cell_path(cfg) == "fused":
+            w = params["embed"].get("head", params["embed"]["tok"])
+            return lm_head_xent(y, w, batch["labels"], batch.get("mask"),
+                                vocab=cfg.vocab)
+        return L.lm_loss(lm_logits(params["embed"], y), batch["labels"],
+                         cfg.vocab, batch.get("mask"))
 
 
 def init_cache(cfg: ModelConfig, batch_size: int, max_len: int):

@@ -23,12 +23,17 @@ from repro.core.clipping import clip_accumulate_tree
 from repro.kernels.cifg_cell import cifg_cell as CK
 from repro.kernels.cifg_cell import ops as CO
 from repro.kernels.dp_clip import dp_clip as DK
+from repro.kernels.lm_head_xent import lm_head_xent as HK
 from repro.models import build
 
 H = 256          # CIFG hidden
 SLOTS = 16       # serving decode slots / padded client batch rows
 CHUNK = 32       # vmapped cohort chunk
 SEQ = 16         # client sequence length
+D = 96           # tied embedding width
+VOCAB, VPAD = 10_000, 10_240
+CLIENT_ROWS = 50 * SEQ   # one client's batch of 50 windows
+HEAD_CHUNK = 25          # the engine's client chunk at the cell's cohort
 EMBED_TILES = (7680, DK.LANES)   # the 10,240 x 96 embedding leaf, tiled
 
 
@@ -53,6 +58,7 @@ def on_chip(monkeypatch):
     """Pick compiled Pallas as the chip would, for whole jitted steps."""
     monkeypatch.setattr(DK, "default_interpret", lambda: False)
     monkeypatch.setattr(CK, "default_interpret", lambda: False)
+    monkeypatch.setattr(HK, "default_interpret", lambda: False)
 
 
 @pytest.fixture(scope="module")
@@ -126,6 +132,26 @@ def test_cifg_sequence(one_chip, direction):
                       argnums=(0, 1))
     _assert_compiled_pallas(fn, _spec(one_chip, (SEQ, 10, 3 * H)),
                             _spec(one_chip, (H, 3 * H)))
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_lm_head_xent(one_chip, direction):
+    """The fused head's kernels at one client's 800 rows x 10,240 x 96,
+    vmapped over a 25-client chunk with per-client tables."""
+    rows = lambda dt=jnp.float32: _spec(one_chip, (HEAD_CHUNK, CLIENT_ROWS,
+                                                   1), dt)
+    args = [_spec(one_chip, (HEAD_CHUNK, CLIENT_ROWS, D), jnp.bfloat16),
+            _spec(one_chip, (HEAD_CHUNK, VPAD, D)), rows(jnp.int32)]
+    if direction == "fwd":
+        fn = lambda y, w, l: HK.xent_fwd(y, w, l, vocab=VOCAB,
+                                         row_block=CLIENT_ROWS,
+                                         interpret=False)
+    else:
+        fn = lambda y, w, l, s, c: HK.xent_bwd(y, w, l, s, c, vocab=VOCAB,
+                                               row_block=CLIENT_ROWS,
+                                               interpret=False)
+        args += [rows(), rows()]
+    _assert_compiled_pallas(jax.vmap(fn), *args)
 
 
 def test_client_step(one_chip, on_chip, model):
