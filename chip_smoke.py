@@ -12,9 +12,10 @@ Phases, in order, all in this one process (the chip belongs to it):
 
 1. device: a TPU is required; there is no CPU fallback.
 2. kernels: the fused ``dp_clip`` clip-accumulate against the pytree
-   reference, and the client loss value-and-grad on the fused Pallas cell
-   and head (``kernels.lm_head_xent``) against the plain autodiff scan and
-   ``lm_loss``, at full width in bf16 compute. Both compiled programs must
+   reference, and the client loss value-and-grad on the fused Pallas cell,
+   head (``kernels.lm_head_xent``) and embedding gradient
+   (``kernels.embed_grad``) against the plain autodiff scan, ``lm_loss``
+   and XLA's scatter, at full width in bf16 compute. Both compiled programs must
    contain ``tpu_custom_call``.
 3. train, device backend: ``repro.launch.train.main`` with 10,000 users,
    1,000 clients per round, 4 rounds, noise multiplier 0.3.

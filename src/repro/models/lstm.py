@@ -21,15 +21,20 @@ model:
   `kernels.lm_head_xent`: the tied-embedding logits, the softmax
   cross-entropy and its backward in one kernel pair, so the (rows, Vpad)
   f32 logits never leave VMEM. ``forward`` (eval, secret-sharer scoring)
-  and serving keep the plain `lm_logits` head;
+  and serving keep the plain `lm_logits` head. The input embedding of
+  ``loss_fn`` and ``forward`` is `kernels.embed_grad.embed_lookup`: the
+  same ``take`` forward, and a one-hot matmul kernel for the table's
+  gradient in place of XLA's scatter-add; serving keeps `embed_tokens`;
 * ``"seq"`` — the same time-fused sequence op with the pure-jnp cell as
   the per-step forward (the fast path on non-TPU backends, where the
   Pallas interpreter would run the cell per step);
 * ``"ref"`` — the pre-split-style plain ``lax.scan`` over the jnp cell
   with ordinary jax autodiff through the scan — the validated reference;
   ``"seq"`` and ``"ref"`` take the head's loss as `lm_logits` followed by
-  `layers.lm_loss` under autodiff;
-* ``"auto"`` (default) — ``"fused"`` on TPU (recurrence and head),
+  `layers.lm_loss`, and the input embedding as `embed_tokens`, under
+  autodiff;
+* ``"auto"`` (default) — ``"fused"`` on TPU (recurrence, head and input
+  embedding),
   ``"seq"`` elsewhere.
 
 Old ``w_gates`` checkpoints load through the one-shot migration shim in
@@ -45,6 +50,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.kernels.cifg_cell import (cifg_cell_ref, cifg_sequence,
                                      cifg_states, cifg_step)
+from repro.kernels.embed_grad.ops import embed_lookup
 from repro.kernels.lm_head_xent.ops import lm_head_xent
 from repro.models import layers as L
 from repro.models.api import Model
@@ -114,7 +120,11 @@ def _recurrence(params, zx, cfg: ModelConfig, remat: bool):
 def _features(params, batch, cfg: ModelConfig, remat: bool):
     """Tokens → the head's input ``y`` (B, S, d) in the compute dtype."""
     cd = jnp.dtype(cfg.compute_dtype)
-    x = embed_tokens(params["embed"], batch["tokens"], cd)  # (B,S,d)
+    with scope("embed"):
+        if resolve_cell_path(cfg) == "fused":
+            x = embed_lookup(params["embed"]["tok"], batch["tokens"], cd)
+        else:
+            x = embed_tokens(params["embed"], batch["tokens"], cd)  # (B,S,d)
     zx = _input_projection(params, x, cd)          # (B,S,3h) — one GEMM
     hs = _recurrence(params, zx, cfg, remat)
     hs = hs.astype(cd)                             # (B,S,hidden)

@@ -31,8 +31,10 @@ SERVE_SPANS = ("serve.admit", "serve.upload", "serve.prefill",
                "serve.tick.book")
 SPANS = FL_SPANS + SERVE_SPANS
 
-# device scopes of the DP-FedAvg round; ``head`` runs inside ``client_sgd``
-SCOPES = ("sample", "client_sgd", "head", "clip_fold", "server_step")
+# device scopes of the DP-FedAvg round; ``embed`` (the input embedding and
+# its gradient) and ``head`` run inside ``client_sgd``
+SCOPES = ("sample", "client_sgd", "embed", "head", "clip_fold",
+          "server_step")
 
 
 def span(name: str, **ids):

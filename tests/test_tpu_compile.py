@@ -9,9 +9,11 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and every test worker imports
 this file. Code that picks interpret mode from ``jax.default_backend()``
 still sees the CPU here, so whole jitted steps are compiled with the two
-``default_interpret`` functions patched to the chip's choice.
+``default_interpret`` functions patched to the chip's choice. The one test
+that runs (`test_embed_grad_on_chip`) is skipped off the chip.
 """
 import os
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +25,9 @@ from repro.core.clipping import clip_accumulate_tree
 from repro.kernels.cifg_cell import cifg_cell as CK
 from repro.kernels.cifg_cell import ops as CO
 from repro.kernels.dp_clip import dp_clip as DK
+from repro.kernels.embed_grad import embed_grad as EK
+from repro.kernels.embed_grad.ops import embed_lookup
+from repro.kernels.embed_grad.ref import embed_lookup_ref
 from repro.kernels.lm_head_xent import lm_head_xent as HK
 from repro.models import build
 
@@ -59,6 +64,7 @@ def on_chip(monkeypatch):
     monkeypatch.setattr(DK, "default_interpret", lambda: False)
     monkeypatch.setattr(CK, "default_interpret", lambda: False)
     monkeypatch.setattr(HK, "default_interpret", lambda: False)
+    monkeypatch.setattr(EK, "default_interpret", lambda: False)
 
 
 @pytest.fixture(scope="module")
@@ -152,6 +158,46 @@ def test_lm_head_xent(one_chip, direction):
                                                interpret=False)
         args += [rows(), rows()]
     _assert_compiled_pallas(jax.vmap(fn), *args)
+
+
+def _embed_vjp(lookup, cd=jnp.bfloat16):
+    """The table's gradient of one client's lookup (800 rows, cotangent in
+    ``cd``), vmapped over a 25-client chunk with per-client tables."""
+    def one(table, tokens, ct):
+        _, vjp = jax.vjp(lambda t: lookup(t, tokens, cd), table)
+        return vjp(ct)[0]
+    return jax.vmap(one)
+
+
+def test_embed_grad(one_chip):
+    _assert_compiled_pallas(
+        _embed_vjp(partial(embed_lookup, interpret=False)),
+        _spec(one_chip, (HEAD_CHUNK, VPAD, D)),
+        _spec(one_chip, (HEAD_CHUNK, CLIENT_ROWS), jnp.int32),
+        _spec(one_chip, (HEAD_CHUNK, CLIENT_ROWS, D), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("cd", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_embed_grad_on_chip(cd):
+    """The compiled kernel's table gradient against the scatter of
+    ``jnp.take``'s autodiff, on the chip at full width: Zipf-like ids
+    (many repeats), every id of the table's last rows, and ids out of
+    range."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("runs on a TPU only")
+    k = jax.random.split(jax.random.PRNGKey(7), 4)
+    tokens = jnp.minimum(jax.random.pareto(k[0], 1.0, (HEAD_CHUNK,
+                                                       CLIENT_ROWS)),
+                         VPAD + 64).astype(jnp.int32) - 1
+    tokens = tokens.at[:, :VPAD - VOCAB].set(jnp.arange(VOCAB, VPAD))
+    tokens = tokens.at[:, -3:].set(jnp.array([VPAD, VPAD + 9, -VPAD - 1]))
+    table = jax.random.normal(k[1], (HEAD_CHUNK, VPAD, D))
+    ct = jax.random.normal(k[2], (HEAD_CHUNK, CLIENT_ROWS, D)).astype(cd)
+    got = jax.jit(_embed_vjp(embed_lookup, cd))(table, tokens, ct)
+    want = jax.jit(_embed_vjp(embed_lookup_ref, cd))(table, tokens, ct)
+    scale = float(jnp.abs(want).max())
+    assert float(jnp.abs(got - want).max()) <= 1e-6 * scale
 
 
 def test_client_step(one_chip, on_chip, model):
